@@ -18,6 +18,10 @@ refreshes ``BENCH_solver.json`` at the repo root — the committed perf
 baseline that ``benchmarks/perf_gate.py`` compares future runs against
 (solve seconds, adder counts, and cost bits per size and engine).
 Exits 1 if the smoke budget/exactness/engine-equivalence gate fails.
+JAX's persistent compilation cache is placed by
+``repro.runtime.compile_cache.enable_compile_cache`` before the first
+bench that compiles (``JAX_COMPILATION_CACHE_DIR`` or ``.jax_cache/`` at
+the repo root); the numpy-only ``smoke`` never imports JAX.
 Roofline numbers live in EXPERIMENTS.md (derived from the dry-run, see
 repro.launch.dryrun).
 """
@@ -51,6 +55,10 @@ def main() -> None:
     only = args[0] if args else None
     # modules are imported lazily so jax-free benches (e.g. `smoke`, which
     # only needs numpy + repro.core) run in minimal environments
+    if only != "smoke":
+        from repro.runtime.compile_cache import enable_compile_cache
+
+        enable_compile_cache()  # before the first compile, which fixes it
     mods = {
         "table2": "table2_random_matrices",
         "table34": "table3_4_resources",

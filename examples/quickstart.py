@@ -3,10 +3,12 @@
     PYTHONPATH=src python examples/quickstart.py
 
 Covers the full core API: solve, verify bit-exactness, compare against
-the hls4ml latency-strategy baseline, execute through the Pallas adder-
-graph kernel, pipeline, and emit synthesizable Verilog.
+the hls4ml latency-strategy baseline, execute the adder graph (through
+the Pallas kernel in interpret mode on the CPU backend, through the jnp
+executor elsewhere), pipeline, and emit synthesizable Verilog.
 """
 
+import jax
 import numpy as np
 
 from repro.core import (
@@ -40,11 +42,15 @@ x = rng.integers(-128, 128, size=(8, 16))
 np.testing.assert_array_equal(sol.evaluate(x), x @ M)
 print("bit-exact vs x @ M: OK")
 
-# --- execute through the levelized Pallas executor (TPU adaptation) ---
+# --- execute the levelized adder graph (TPU adaptation) ---
+# The Pallas kernel runs only on the CPU backend, in interpret mode: it
+# does not lower for the TPU, where the jnp executor (what Flow serves) runs.
 tables = compile_tables(sol.program)
-y = adder_graph_apply(tables, x.astype(np.int32), use_pallas=True, block_b=8)
+on_cpu = jax.default_backend() == "cpu"
+y = adder_graph_apply(tables, x.astype(np.int32), use_pallas=on_cpu, block_b=8)
 np.testing.assert_array_equal(np.asarray(y), x @ M)
-print("Pallas adder-graph kernel (interpret mode): OK")
+executor = "Pallas kernel, interpret mode" if on_cpu else "jnp executor"
+print(f"adder graph on {jax.default_backend()} ({executor}): OK")
 
 # --- pipelining + RTL ---
 rep = pipeline(sol.program, max_delay_per_stage=5)

@@ -208,7 +208,10 @@ class CompileConfig(_ConfigBase):
                          trees, the hls4ml latency-strategy baseline).
     max_delay_per_stage  pipelining budget per register stage.
     use_pallas           execute CMVMs through the Pallas adder-graph
-                         kernel instead of the jnp gather executor.
+                         kernel instead of the jnp gather executor
+                         (CPU backend only, in interpret mode: the
+                         kernel does not lower for the TPU, so
+                         ``build_steps`` raises there).
     jobs                 solver thread-pool width (None = cpu_count,
                          1 = in-line serial); never changes the bits —
                          serial fallbacks are recorded loudly in

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 
 def _adder_graph_kernel(x_ref, instr_ref, outs_ref, o_ref, *, level_bounds):
     v = x_ref[...].T.astype(jnp.int32)  # [n_inputs, block_b]
@@ -43,11 +45,13 @@ def _adder_graph_kernel(x_ref, instr_ref, outs_ref, o_ref, *, level_bounds):
 
 
 @functools.partial(jax.jit, static_argnames=("tables", "block_b", "interpret"))
-def adder_graph_pallas(tables, x: jnp.ndarray, block_b: int = 256, interpret: bool = True):
+def adder_graph_pallas(tables, x: jnp.ndarray, block_b: int = 256, interpret: bool | None = None):
     """Run the adder graph on int32 inputs [batch, n_in] via pallas_call.
 
-    ``interpret=True`` executes the kernel body on CPU (bit-exact); on a
-    real TPU pass ``interpret=False``.
+    ``interpret=None`` interprets the kernel body on the CPU backend
+    (bit-exact) and compiles it elsewhere.  It does not lower through
+    Mosaic yet (the per-level ``jnp.take`` gathers are refused), so
+    ``build_steps`` refuses ``use_pallas=True`` off the CPU.
     """
     batch, n_in = x.shape
     n_out = tables.n_outputs
@@ -69,6 +73,6 @@ def adder_graph_pallas(tables, x: jnp.ndarray, block_b: int = 256, interpret: bo
         ],
         out_specs=pl.BlockSpec((block_b, n_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, n_out), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x.astype(jnp.int32), instr, outs)
     return out[:batch]

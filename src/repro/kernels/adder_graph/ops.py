@@ -128,14 +128,15 @@ def adder_graph_apply(
     *,
     use_pallas: bool = False,
     block_b: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Evaluate y = x @ M through the optimized adder graph.
 
     x: int array [..., n_inputs] (integer grid). Returns int32
-    [..., n_outputs]. ``use_pallas`` selects the Pallas TPU kernel
-    (interpret=True executes it on CPU for validation); the default is
-    the pure-jnp reference, which XLA fuses well on any backend.
+    [..., n_outputs]. ``use_pallas`` selects the Pallas kernel (run in
+    interpret mode on the CPU backend, see ``resolve_interpret``); the
+    default is the pure-jnp reference, which XLA fuses well on any
+    backend.
     """
     from .kernel import adder_graph_pallas
     from .ref import adder_graph_ref

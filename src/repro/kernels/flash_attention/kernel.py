@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -74,7 +76,7 @@ def flash_attention_pallas(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
@@ -99,5 +101,5 @@ def flash_attention_pallas(
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b_, h, iq: (b_, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, jnp.asarray(offset, jnp.int32).reshape(1))

@@ -26,7 +26,7 @@ def flash_attention(
     scale: float | None = None,
     offset=None,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_q: int = 128,
     block_k: int = 128,
 ) -> jnp.ndarray:

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 
 def _qmm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, *, n_k):
     ik = pl.program_id(2)
@@ -50,7 +52,7 @@ def quant_matmul_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     m, k = x.shape
     _, n = w.shape
@@ -69,5 +71,5 @@ def quant_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w, x_scale, w_scale)

@@ -14,7 +14,7 @@ def quant_matmul(
     x_scale: jnp.ndarray,
     w_scale: jnp.ndarray,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 256,

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 
 def _ssm_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, h0_ref, y_ref, hout_ref, *, seq_len):
     a = a_ref[...]  # [tile_d, N]
@@ -47,7 +49,7 @@ def selective_scan_pallas(
     a: jnp.ndarray,  # f32 [D, N]
     h0: jnp.ndarray,  # f32 [B, D, N]
     tile_d: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     b, s, d = dt.shape
     n = a.shape[1]
@@ -75,6 +77,6 @@ def selective_scan_pallas(
             jax.ShapeDtypeStruct((b, s, d), jnp.float32),
             jax.ShapeDtypeStruct((b, d, n), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(dt, bmat, cmat, x, a, h0)
     return y, h_out

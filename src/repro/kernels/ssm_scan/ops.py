@@ -17,7 +17,7 @@ def selective_scan(
     h0: jnp.ndarray,
     use_pallas: bool = False,
     tile_d: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Mamba-1 recurrence. Returns (y [B,S,D], h_final [B,D,N])."""
     # kernel contract is f32 (the scan state must be f32 regardless of

@@ -127,18 +127,8 @@ def parse_module(hlo: str) -> dict[str, Computation]:
         # operands: first (...) group after the opcode
         rest = rhs[op_m.end() - 1 :]
         ops_m = _OPERANDS_RE.match(rest)
-        operands = []
-        if ops_m:
-            for tok in ops_m.group(1).split(","):
-                tok = tok.strip()
-                if "%" in tok:
-                    # older XLA print options inline operand shapes
-                    # ("f32[512,1024]{1,0} %param"); commas inside the
-                    # shape split it into junk pieces, but exactly one
-                    # piece carries the %name.
-                    operands.extend(re.findall(r"%([\w.\-]+)", tok))
-                elif re.match(r"^[\w.\-]+$", tok) and not tok[0].isdigit():
-                    operands.append(tok)
+        # XLA prints operands as "%name" ("add(%a, %b)")
+        operands = re.findall(r"%([\w.\-]+)", ops_m.group(1)) if ops_m else []
         name = m.group(2)
         instr = Instr(name, opcode, result_text, operands, s, is_root=bool(m.group(1)))
         if opcode == "parameter":

@@ -16,16 +16,10 @@ import jax
 
 
 def _make_auto_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where supported.
-
-    ``jax.sharding.AxisType`` appeared after 0.4.x; on older versions
-    every axis is implicitly auto-sharded, so omitting the kwarg is
-    semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis explicitly Auto-sharded."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

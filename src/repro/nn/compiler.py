@@ -26,6 +26,7 @@ import time
 from dataclasses import dataclass, field
 from collections.abc import Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -203,7 +204,17 @@ def build_steps(specs: list[StepSpec], tables: list, use_pallas: bool = False):
     ``tables``: the design's per-unique-CMVM ``AdderGraphTables`` list.
     Both ``compile_model`` and the artifact loader go through this
     single builder, which is what makes save->load bit-exact.
+
+    ``use_pallas=True`` runs only on the CPU backend (interpret mode):
+    the adder-graph Pallas kernel does not lower through Mosaic, so any
+    other backend raises ``NotImplementedError`` here, at build time.
     """
+    if use_pallas and jax.default_backend() != "cpu":
+        raise NotImplementedError(
+            "the adder-graph Pallas kernel (repro.kernels.adder_graph) does not "
+            f"compile for the {jax.default_backend()!r} backend; build the design "
+            "with use_pallas=False to run the jnp executor"
+        )
     return [_build_step(s, tables, use_pallas) for s in specs]
 
 

@@ -101,13 +101,6 @@ def test_virtual_mesh_active(dist_result):
 
 
 def test_sharded_train_step_matches_reference(dist_result):
-    import jax
-
-    if not hasattr(jax.sharding, "AxisType"):
-        # jax < 0.5 has no explicit mesh axis types; sharded reductions
-        # on virtual CPU devices then reassociate float sums, so strict
-        # loss parity only holds on versions with Auto axis types.
-        pytest.skip("strict sharded-numerics parity needs jax.sharding.AxisType")
     assert abs(dist_result["ref_loss"] - dist_result["sh_loss"]) < 1e-4
     assert dist_result["param_dmax"] < 5e-5
 

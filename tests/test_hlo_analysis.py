@@ -15,7 +15,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import analyze
 from repro.launch.mesh import make_test_mesh
 
-mesh = make_test_mesh(2, 4)  # version-compatible Auto-axis mesh
+mesh = make_test_mesh(2, 4)  # Auto-axis mesh
 out = {}
 
 # 1) scan with known trip count: flops must be trips * body
