@@ -161,10 +161,14 @@ class CompiledDesign:
 
     # ------------------------------------------------------------------
     def forward_int(self, x_int: jnp.ndarray) -> jnp.ndarray:
-        """Run the integer pipeline. x_int: [batch, *in_shape] grid ints."""
+        """Run the integer pipeline. x_int: [batch, *in_shape] grid ints.
+
+        Each step runs under ``jax.named_scope(f"step{i}_{kind}")``, which
+        names its ops in the compiled program's ``op_name`` metadata."""
         v = x_int.reshape(x_int.shape[0], -1).astype(jnp.int32)
-        for step in self.steps:
-            v = step(v)
+        for i, (spec, step) in enumerate(zip(self.step_specs, self.steps, strict=True)):
+            with jax.named_scope(f"step{i}_{spec.kind}"):
+                v = step(v)
         return v.reshape(x_int.shape[0], *self.out_shape)
 
     def forward(self, x: jnp.ndarray) -> jnp.ndarray:

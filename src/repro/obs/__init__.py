@@ -6,7 +6,10 @@ engine, and the benchmarks:
 ``repro.obs.trace``
     Low-overhead span tracer with per-thread ring buffers and a Chrome
     trace-event / Perfetto JSON exporter.  Disabled by default; enable
-    with ``REPRO_TRACE=1`` or :func:`trace.set_enabled`.
+    with ``REPRO_TRACE=1`` or :func:`trace.set_enabled`.  Once a module
+    that imports jax registers the profiler sink
+    (:func:`trace.set_profiler_sink`), spans also land in any active
+    JAX profiler session.
 
 ``repro.obs.metrics``
     Process-wide registry of counters / gauges / histograms with

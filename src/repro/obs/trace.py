@@ -1,4 +1,5 @@
-"""Low-overhead span tracer with Chrome trace-event / Perfetto export.
+"""Low-overhead span tracer with two sinks: its own ring, exported as a
+Chrome trace-event / Perfetto document, and the JAX profiler.
 
 Usage::
 
@@ -11,11 +12,18 @@ Design constraints (this sits inside the solver hot path and the serve
 dispatcher loop):
 
 * **Disabled path is a shared no-op context manager.**  ``span(...)``
-  returns a module-level singleton when tracing is off — no object
-  allocation, no clock read, no thread-local lookup.  The only residual
-  cost is the call itself plus the kwargs dict, which is why call sites
-  keep spans at *phase* granularity (per solve / per batch), never
-  per-element.
+  returns a module-level singleton when neither sink is on — no object
+  allocation, no clock read, no thread-local lookup; with the profiler
+  sink registered, one ``is_enabled()`` call decides.  The only
+  residual cost is the call itself plus the kwargs dict, which is why
+  call sites keep spans at *phase* granularity (per solve / per batch),
+  never per-element.
+
+* **Profiler sink.**  Code that already imports jax registers
+  ``jax.profiler.TraceAnnotation`` with :func:`set_profiler_sink`; while
+  a profiler session is active every span is then also a TraceMe event
+  (attrs as its stats) in the profile's ``/host:CPU`` plane, on the
+  clock of the device planes.  This module itself never imports jax.
 
 * **Per-thread ring buffers, no locks on the record path.**  Each thread
   owns a bounded event ring it alone writes; the module lock is taken
@@ -49,8 +57,8 @@ __all__ = [
     "instant",
     "reset",
     "export",
-    "export_chrome_trace",
     "n_events",
+    "set_profiler_sink",
 ]
 
 DEFAULT_CAPACITY = 65536
@@ -64,6 +72,9 @@ _tls = threading.local()
 
 _capacity = int(os.environ.get("REPRO_TRACE_CAPACITY", DEFAULT_CAPACITY))
 _enabled = os.environ.get("REPRO_TRACE", "").strip().lower() not in ("", "0", "false", "off")
+# TraceMe-like class (``name, **attrs`` constructor, context manager, static
+# ``is_enabled()``), or None while no profiler sink is registered
+_profiler: Any = None
 
 
 def enabled() -> bool:
@@ -75,6 +86,14 @@ def set_enabled(flag: bool) -> None:
     """Turn span recording on/off process-wide (also: ``REPRO_TRACE=1``)."""
     global _enabled
     _enabled = bool(flag)
+
+
+def set_profiler_sink(trace_me: Any) -> None:
+    """Also write every span as ``trace_me(name, **attrs)`` whenever
+    ``trace_me.is_enabled()`` (a profiler session is active).  Pass
+    ``jax.profiler.TraceAnnotation``; ``None`` unregisters."""
+    global _profiler
+    _profiler = trace_me
 
 
 def set_capacity(capacity: int) -> None:
@@ -146,18 +165,26 @@ class span:
     """Record one Complete ("X") event spanning the ``with`` body.
 
     ``span(name, **attrs)`` — attrs land in the event's ``args`` and show
-    up in the Perfetto slice details pane.  When tracing is disabled this
-    returns a shared no-op singleton (no allocation).
+    up in the Perfetto slice details pane.  While a profiler session is
+    active and a sink is registered, the body is also a profiler event.
+    With the ring off this returns the profiler's event itself, or, with
+    no session either, a shared no-op singleton (no allocation).
     """
 
-    __slots__ = ("name", "args", "t0", "depth")
+    __slots__ = ("name", "args", "t0", "depth", "prof")
 
-    def __new__(cls, name: str, **attrs: Any) -> "span | _NoopSpan":
+    def __new__(cls, name: str, **attrs: Any) -> Any:
+        prof = (
+            _profiler(name, **attrs)
+            if _profiler is not None and _profiler.is_enabled()
+            else None
+        )
         if not _enabled:
-            return _NOOP
+            return _NOOP if prof is None else prof
         self = object.__new__(cls)
         self.name = name
         self.args = attrs or None
+        self.prof = prof
         return self
 
     def __init__(self, name: str, **attrs: Any) -> None:
@@ -166,6 +193,8 @@ class span:
         pass
 
     def __enter__(self) -> "span":
+        if self.prof is not None:
+            self.prof.__enter__()
         b = _buf()
         self.depth = len(b.stack)
         b.stack.append(self.name)
@@ -179,11 +208,14 @@ class span:
             b.stack.pop()
         # (name, ts_us, dur_us, depth, args) — dur None marks an instant
         b.push((self.name, (self.t0 - _EPOCH) * 1e6, (t1 - self.t0) * 1e6, self.depth, self.args))
+        if self.prof is not None:
+            self.prof.__exit__(*exc)
         return False
 
 
 def instant(name: str, **attrs: Any) -> None:
-    """Record a zero-duration instant event (rendered as an arrow mark)."""
+    """Record a zero-duration instant event (rendered as an arrow mark).
+    Instants go to the ring only: the profiler sink takes spans."""
     if not _enabled:
         return
     b = _buf()
@@ -253,7 +285,3 @@ def export(path: str | None = None) -> dict:
         with open(path, "w") as fh:
             json.dump(doc, fh)
     return doc
-
-
-# canonical exporter name used by docs/benchmarks; `export` is the short form
-export_chrome_trace = export

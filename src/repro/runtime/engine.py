@@ -22,9 +22,15 @@ or cold-started from a ``save_design`` artifact) gets:
     integer forward pass (shared by all shards) compiles once per
     bucket and every batch is padded to the next bucket;
   * per-request latency accounting (submit -> result, p50/p95/p99,
-    throughput) plus per-stage accounting (queue wait / batch-form /
-    pad / dispatch / copy-out) and per-shard counters, merged across
-    shards in ``stats()``.
+    throughput) plus per-stage accounting (queue wait per request; per
+    batch idle / batch-form / pad / dispatch / copy-out / observe, which
+    tile the dispatcher thread's wall time) and per-shard counters,
+    merged across shards in ``stats()``;
+  * one ``repro.obs.trace`` span per dispatcher stage and batch
+    (``serve.idle``, ``serve.batch_form``, then ``serve.batch`` holding
+    ``serve.pad`` / ``serve.dispatch`` / ``serve.copy_out`` /
+    ``serve.observe``), which also land in an active JAX profiler
+    session beside the runtime's own events.
 
 Requests are single samples on the integer input grid (``in_shape``,
 as ``CompiledDesign.forward_int`` consumes them); ``submit`` returns a
@@ -88,6 +94,9 @@ from ..obs.metrics import Histogram, get_registry, render_prometheus
 from .artifact import load_design
 from .metrics import LatencyRecorder, StageAccumulator
 from .resilience import CircuitBreaker
+
+# spans land in the JAX profiler's trace while a session is active
+trace.set_profiler_sink(jax.profiler.TraceAnnotation)
 
 
 def _serve_config_from_legacy(legacy: dict) -> ServeConfig:
@@ -216,6 +225,8 @@ class _Shard(threading.Thread):
         self.flight = FlightRecorder(capacity=2048, slow_k=16)
         self._tid_seq = itertools.count()
         self._tid_base = idx << 40
+        self._batch_seq = itertools.count()  # numbers this shard's serve.batch spans
+        self._t_mark = 0.0  # where the dispatcher's current stage began
         self.n_batches = 0
         self.n_rejected = 0  # guarded by self._lock (shared with submitters)
         self.n_shed = 0  # guarded by self._lock (submitters + dispatcher)
@@ -223,7 +234,8 @@ class _Shard(threading.Thread):
         self.n_fallback_batches = 0  # dispatcher-only writer
         self._occupancy_sum = 0.0
         self.bucket_hits: dict[int, int] = {b: 0 for b in runner.buckets}
-        self._stop = threading.Event()
+        # not `_stop`: that would shadow threading.Thread._stop()
+        self._stop_event = threading.Event()
         self._drained = threading.Event()
         # crash state: flipped once by _on_crash, read under the lock by
         # submitters and lock-free by the supervisor
@@ -352,37 +364,57 @@ class _Shard(threading.Thread):
     # -- dispatcher ----------------------------------------------------
     def run(self) -> None:
         try:
+            self._t_mark = time.perf_counter()
             while True:
                 self.heartbeat = time.perf_counter()
                 fault_point("serve.dispatcher")
                 batch, t_first = self._collect()
                 if batch:
-                    with trace.span("serve.batch", shard=self.idx, n=len(batch)):
+                    with trace.span(
+                        "serve.batch", shard=self.idx, seq=next(self._batch_seq),
+                        bucket=self._bucket(len(batch)), n=len(batch),
+                        first_tid=batch[0].tid, last_tid=batch[-1].tid,
+                    ):
                         self._execute(batch, t_first)
-                elif self._stop.is_set():
+                elif self._stop_event.is_set():
                     break
             self._fail_pending(self._closed_error)
             self._drained.set()
         except BaseException as e:  # dispatcher death: clean up, never strand
             self._on_crash(e)
 
+    def _lap(self, stage: str) -> float:
+        """Charge the time since the last lap to ``stage`` (one batch)
+        and return the clock reading that ends it.  The laps of one
+        loop run back to back, so the batch stages tile the dispatcher
+        thread's wall time; what a failed or wholly shed batch leaves
+        uncharged falls into the next ``idle``."""
+        t = time.perf_counter()
+        dt = t - self._t_mark
+        self._t_mark = t
+        self.stage.add(stage, dt)
+        self.stage_hist[stage].observe(dt * 1e6)
+        return t
+
     def _collect(self) -> tuple[list[_Request], float]:
         with self._lock:
-            while not self._pending:
-                if self._stop.is_set():
-                    return [], 0.0
-                self.heartbeat = time.perf_counter()
-                self._not_empty.wait(0.05)
-            t_first = time.perf_counter()
-            if len(self._pending) < self.max_batch and not self._stop.is_set():
-                deadline = t_first + self.max_wait_s
-                while len(self._pending) < self.max_batch:
-                    rem = deadline - time.perf_counter()
-                    if rem <= 0 or self._stop.is_set():
-                        break
-                    self._not_empty.wait(min(rem, 0.02))
-            n = min(len(self._pending), self.max_batch)
-            batch = [self._pending.popleft() for _ in range(n)]
+            with trace.span("serve.idle", shard=self.idx):
+                while not self._pending:
+                    if self._stop_event.is_set():
+                        return [], 0.0
+                    self.heartbeat = time.perf_counter()
+                    self._not_empty.wait(0.05)
+            t_first = self._lap("idle")
+            with trace.span("serve.batch_form", shard=self.idx):
+                if len(self._pending) < self.max_batch and not self._stop_event.is_set():
+                    deadline = t_first + self.max_wait_s
+                    while len(self._pending) < self.max_batch:
+                        rem = deadline - time.perf_counter()
+                        if rem <= 0 or self._stop_event.is_set():
+                            break
+                        self._not_empty.wait(min(rem, 0.02))
+                n = min(len(self._pending), self.max_batch)
+                batch = [self._pending.popleft() for _ in range(n)]
             self._not_full.notify_all()
             return batch, t_first
 
@@ -455,83 +487,92 @@ class _Shard(threading.Thread):
         breaker.record(ok=True, probe=probe)
         return y, False
 
-    def _execute(self, batch: list[_Request], t_first: float) -> None:
-        t_formed = time.perf_counter()
-        # claim the futures; drop any the client cancelled while queued,
-        # shed any whose deadline expired while they sat in the queue
-        claimed: list[_Request] = []
-        expired: list[_Request] = []
-        for r in batch:
-            if not r.future.set_running_or_notify_cancel():
-                continue
-            if r.deadline is not None and t_formed >= r.deadline:
-                expired.append(r)
-            else:
-                claimed.append(r)
-        self.stage.add("batch_form", t_formed - t_first)
-        slots = [r.slot for r in batch]
-        if expired:
-            with self._lock:
-                self.n_shed += len(expired)
-            for r in expired:
-                r.future.set_exception(self._deadline_error())
-        if not claimed:
-            self._free_slots(slots)
-            return
-        self.stage.add(
-            "queue_wait",
-            sum(t_formed - r.t_submit for r in claimed),
-            len(claimed),
-        )
-        n = len(claimed)
-        b = self._bucket(n)
-        x = self._scratch[b]
-        self._executing = claimed  # crash handler fails these if we die here
-        try:
-            try:
-                fault_point("serve.gather")
-                x[:n] = self.slab[[r.slot for r in claimed]]
-                if n < b:
-                    x[n:] = 0
-            finally:
-                self._free_slots(slots)  # slots recycle even on failure
-            t_pad = time.perf_counter()
-            self.stage.add("pad", t_pad - t_formed)
-            y, used_fallback = self._dispatch(x)
-        except ThreadKillFault:
-            raise  # run()'s crash handler resolves self._executing
-        except Exception as e:  # resolve futures instead of killing the thread
-            self._executing = []
-            if isinstance(e, CircuitOpenError):
-                self.n_fast_failed += len(claimed)
-            for r in claimed:
-                r.future.set_exception(e)
-            return
+    def _fail_batch(self, claimed: list[_Request], e: Exception) -> None:
+        """Resolve a failed batch's futures instead of killing the thread."""
         self._executing = []
-        t_done = time.perf_counter()
-        self.stage.add("dispatch", t_done - t_pad)
-        if used_fallback:
-            self.n_fallback_batches += 1
-        lats = []
-        for i, r in enumerate(claimed):
-            r.future.set_result(y[i])
-            lats.append(t_done - r.t_submit)
-        self.metrics.record_many(lats, t_done)
-        self.n_batches += 1
-        # counted only on success, keeping sum(bucket_hits) == n_batches
-        self.bucket_hits[b] += 1
-        jc = self.runner.jit_compiles
-        if not used_fallback and not jc[b]:
-            jc[b] = 1  # first dispatch of this shape compiled (any shard)
-        self._occupancy_sum += n / b
-        t_out = time.perf_counter()
-        self.stage.add("copy_out", t_out - t_done)
-        self._observe_batch(claimed, lats, b, n, t_first, t_formed, t_pad, t_done, t_out)
+        if isinstance(e, CircuitOpenError):
+            self.n_fast_failed += len(claimed)
+        for r in claimed:
+            r.future.set_exception(e)
+
+    def _execute(self, batch: list[_Request], t_first: float) -> None:
+        t_formed = self._lap("batch_form")
+        with trace.span("serve.pad"):
+            # claim the futures; drop any the client cancelled while queued,
+            # shed any whose deadline expired while they sat in the queue
+            claimed: list[_Request] = []
+            expired: list[_Request] = []
+            for r in batch:
+                if not r.future.set_running_or_notify_cancel():
+                    continue
+                if r.deadline is not None and t_formed >= r.deadline:
+                    expired.append(r)
+                else:
+                    claimed.append(r)
+            slots = [r.slot for r in batch]
+            if expired:
+                with self._lock:
+                    self.n_shed += len(expired)
+                for r in expired:
+                    r.future.set_exception(self._deadline_error())
+            if not claimed:
+                self._free_slots(slots)
+                return
+            self.stage.add(
+                "queue_wait",
+                sum(t_formed - r.t_submit for r in claimed),
+                len(claimed),
+            )
+            n = len(claimed)
+            b = self._bucket(n)
+            x = self._scratch[b]
+            # the crash handler fails these if we die before they resolve;
+            # a ThreadKillFault passes the guards below to run()'s handler
+            self._executing = claimed
+            try:
+                try:
+                    fault_point("serve.gather")
+                    x[:n] = self.slab[[r.slot for r in claimed]]
+                    if n < b:
+                        x[n:] = 0
+                finally:
+                    self._free_slots(slots)  # slots recycle even on failure
+            except Exception as e:
+                self._fail_batch(claimed, e)
+                return
+        t_pad = self._lap("pad")
+        with trace.span("serve.dispatch"):
+            try:
+                y, used_fallback = self._dispatch(x)
+            except Exception as e:
+                self._fail_batch(claimed, e)
+                return
+        self._executing = []
+        t_done = self._lap("dispatch")
+        with trace.span("serve.copy_out"):
+            if used_fallback:
+                self.n_fallback_batches += 1
+            lats = []
+            for i, r in enumerate(claimed):
+                r.future.set_result(y[i])
+                lats.append(t_done - r.t_submit)
+            self.metrics.record_many(lats, t_done)
+            self.n_batches += 1
+            # counted only on success, keeping sum(bucket_hits) == n_batches
+            self.bucket_hits[b] += 1
+            jc = self.runner.jit_compiles
+            if not used_fallback and not jc[b]:
+                jc[b] = 1  # first dispatch of this shape compiled (any shard)
+            self._occupancy_sum += n / b
+        t_out = self._lap("copy_out")
+        with trace.span("serve.observe"):
+            self._observe_batch(claimed, lats, b, n, t_first, t_formed, t_pad, t_done, t_out)
+        self._lap("observe")
 
     def _observe_batch(
         self, claimed, lats, b, n, t_first, t_formed, t_pad, t_done, t_out
     ) -> None:
-        """Feed the per-stage histograms, the flight recorder, and the
+        """Feed the queue-wait histogram, the flight recorder, and the
         process-registry gauges after a successful batch.  This thread is
         the sole writer of all three, so the path stays lock-free; the
         batch-shared stage times are charged to every request's flight
@@ -540,12 +581,7 @@ class _Shard(threading.Thread):
         pad_us = (t_pad - t_formed) * 1e6
         disp_us = (t_done - t_pad) * 1e6
         out_us = (t_out - t_done) * 1e6
-        hists = self.stage_hist
-        hists["batch_form"].observe(bf_us)
-        hists["pad"].observe(pad_us)
-        hists["dispatch"].observe(disp_us)
-        hists["copy_out"].observe(out_us)
-        qh = hists["queue_wait"]
+        qh = self.stage_hist["queue_wait"]
         fl = self.flight
         ts_us = t_done * 1e6
         for r, lat in zip(claimed, lats):
@@ -570,7 +606,7 @@ class _Shard(threading.Thread):
 
     # -- control -------------------------------------------------------
     def initiate_stop(self) -> None:
-        self._stop.set()
+        self._stop_event.set()
         with self._lock:
             self._not_empty.notify_all()
             self._not_full.notify_all()
@@ -619,7 +655,7 @@ class _Supervisor(threading.Thread):
                 sh = r.shards[idx]
                 if sh.ident is None:
                     continue  # not started yet
-                if (sh.dead or not sh.is_alive()) and not sh._stop.is_set():
+                if (sh.dead or not sh.is_alive()) and not sh._stop_event.is_set():
                     r._revive(idx, sh)
 
 

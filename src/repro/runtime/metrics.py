@@ -11,17 +11,21 @@ locks) and merges them with :meth:`LatencyRecorder.merged_snapshot`.
 ``StageAccumulator`` is the per-stage side of the story — in the spirit
 of rule4ml / hft-latency-lab stage-timestamped accounting ("measure
 where the time actually goes"): each dispatched batch contributes wall
-seconds to the five serving stages
+seconds to the serving stages
 
     queue_wait   submit -> dequeue, summed per request
+    idle         dispatcher waiting until a request is pending
     batch_form   batching window after the first request of the batch
-    pad          slab gather + zero-pad into the bucket-shaped scratch
+    pad          claim + slab gather + zero-pad into the bucket scratch
     dispatch     jitted forward call (incl. blocking on the result)
     copy_out     future resolution + latency recording
+    observe      bookkeeping: flight records, histograms, gauges
 
 so ``stats()`` can report where a request's latency budget actually
-goes instead of one opaque end-to-end number.  Accumulators are
-single-writer (one per shard) and merged at snapshot time.
+goes instead of one opaque end-to-end number.  The six per-batch stages
+(all but ``queue_wait``) run back to back on the dispatcher thread and
+tile its wall time.  Accumulators are single-writer (one per shard) and
+merged at snapshot time.
 """
 
 from __future__ import annotations
@@ -165,7 +169,9 @@ class StageAccumulator:
     unit so the two kinds stay interpretable).
     """
 
-    STAGES = ("queue_wait", "batch_form", "pad", "dispatch", "copy_out")
+    STAGES = (
+        "queue_wait", "idle", "batch_form", "pad", "dispatch", "copy_out", "observe",
+    )
 
     def __init__(self):
         self.total_s = {s: 0.0 for s in self.STAGES}

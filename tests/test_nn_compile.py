@@ -101,3 +101,26 @@ def test_quantized_training_step_reduces_loss():
         g = jax.grad(loss_fn)(p2)
         p2 = jax.tree.map(lambda a, gg: a - lr * gg, p2, g)
     assert loss_fn(p2) < loss0
+
+
+def test_forward_int_names_each_step_in_the_compiled_program():
+    """Every step runs under ``step{i}_{kind}``: the scopes reach the
+    compiled program's op_name metadata and change no output bit."""
+    model, in_shape, in_quant = models.jet_tagger()
+    params, _ = init_params(jax.random.PRNGKey(3), model, in_shape)
+    design = compile_model(model, params, in_shape, in_quant, dc=2)
+    q = in_quant.qint
+    x = np.random.default_rng(3).integers(q.lo, q.hi + 1, size=(64, *in_shape)).astype(np.int32)
+    text = jax.jit(design.forward_int).lower(x).compile().as_text()
+    for i, spec in enumerate(design.step_specs):
+        assert f'op_name="jit(forward_int)/step{i}_{spec.kind}/' in text
+
+    def unscoped(x_int):
+        v = x_int.reshape(x_int.shape[0], -1).astype(jnp.int32)
+        for step in design.steps:
+            v = step(v)
+        return v.reshape(x_int.shape[0], *design.out_shape)
+
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(design.forward_int)(x)), np.asarray(jax.jit(unscoped)(x))
+    )

@@ -8,6 +8,7 @@ surface real numbers without perturbing results."""
 
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -51,6 +52,48 @@ def test_disabled_span_is_shared_noop():
         pass
     trace.instant("tick")
     assert trace.n_events() == 0
+
+
+def test_span_with_profiler_sink_and_no_session_is_shared_noop():
+    import repro.runtime.engine  # noqa: F401  (registers the profiler sink)
+
+    assert trace._profiler is jax.profiler.TraceAnnotation
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert trace.span("x", k=1) is trace._NOOP
+
+
+def _host_events(log_dir):
+    """(thread line, name, start_ns, end_ns, stats) of every event in the
+    profile's ``/host:CPU`` plane; a line is one thread (several may share
+    a name, so it is keyed by position)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    out = []
+    for f in glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True):
+        for plane in ProfileData.from_file(f).planes:
+            if plane.name == "/host:CPU":
+                for k, line in enumerate(plane.lines):
+                    for e in line.events:
+                        out.append(((f, k), e.name, e.start_ns,
+                                    e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def test_span_writes_ring_and_profiler_under_a_session(tmp_path):
+    import repro.runtime.engine  # noqa: F401  (registers the profiler sink)
+
+    trace.set_enabled(True)
+    with jax.profiler.trace(str(tmp_path)):
+        with trace.span("obs.outer", k=1):
+            with trace.span("obs.inner"):
+                pass
+    ring = {e["name"]: e for e in trace.export()["traceEvents"] if e["ph"] == "X"}
+    assert ring["obs.outer"]["args"] == {"k": 1} and "obs.inner" in ring
+    prof = {name: (lo, hi, st) for _, name, lo, hi, st in _host_events(tmp_path)}
+    assert prof["obs.outer"][2] == {"k": 1}
+    assert prof["obs.outer"][0] <= prof["obs.inner"][0] <= prof["obs.inner"][1] <= prof["obs.outer"][1]
 
 
 def test_disabled_tracing_is_bit_exact_on_solver():
@@ -388,3 +431,40 @@ def test_engine_stats_carry_flight_and_metrics_text(design):
     for family in ("serve_batches_total", "serve_stage_us_bucket",
                    "serve_queue_depth"):
         assert family in text
+
+
+def test_engine_stage_spans_land_in_the_profiler_host_plane(design, tmp_path):
+    """Under a profiler session (ring off), each batch writes
+    ``serve.batch`` with its args and the four stages nested in it, plus
+    ``serve.idle`` and ``serve.batch_form``; a flight record's trace id
+    falls in the id range of one batch of its shard."""
+    xs = np.random.default_rng(3).integers(-8, 8, size=(40, 8)).astype(np.int32)
+    with Deployment(ServeConfig(max_batch=8, max_wait_us=100.0, shards=2)) as dep:
+        dep.register("m", design, warmup=True)
+        with jax.profiler.trace(str(tmp_path)):
+            for f in [dep.submit("m", x) for x in xs]:
+                f.result(30)
+            # let the last batch finish its bookkeeping inside the session
+            for _ in range(500):
+                ps = dep.stats("m")
+                if ps["per_stage"]["observe"]["count"] == ps["n_batches"]:
+                    break
+                time.sleep(0.01)
+            time.sleep(0.05)
+        stats = dep.stats("m")
+    events = _host_events(tmp_path)
+    batches = [e for e in events if e[1] == "serve.batch"]
+    assert batches
+    for line, _, lo, hi, st in batches:
+        assert set(st) == {"shard", "seq", "bucket", "n", "first_tid", "last_tid"}
+        assert st["last_tid"] - st["first_tid"] == st["n"] - 1
+        inner = {e[1] for e in events if e[0] == line and lo <= e[2] and e[3] <= hi}
+        assert {"serve.pad", "serve.dispatch", "serve.copy_out", "serve.observe"} <= inner
+    assert sum(e[4]["n"] for e in batches) == len(xs)
+    names = {e[1] for e in events}
+    assert {"serve.idle", "serve.batch_form"} <= names
+    for rec in stats["flight"]["slowest"]:
+        assert any(
+            st["shard"] == rec["shard"] and st["first_tid"] <= rec["trace_id"] <= st["last_tid"]
+            for *_, st in batches
+        )

@@ -482,3 +482,56 @@ def test_submit_after_stop_fails_fast(designs):
     for f in futs:
         with pytest.raises(EngineClosedError, match="shut down"):
             f.result(1)
+
+
+def test_dispatcher_stages_cover_its_wall_time(designs):
+    """idle + batch_form + pad + dispatch + copy_out + observe of each
+    shard add up to the time between two stats() calls (within 2% or
+    2 ms) while a client keeps the shards busy with bursts of submits."""
+    per_batch = ("idle", "batch_form", "pad", "dispatch", "copy_out", "observe")
+    xs = _samples(64)
+    stop = threading.Event()
+    cfg = ServeConfig(max_batch=8, max_wait_us=100.0, shards=2)
+    with ServeEngine(config=cfg) as eng:
+        eng.register("a", designs["a"], warmup=True)
+
+        def bursts():
+            while not stop.is_set():
+                for f in eng.submit_batch("a", xs):
+                    f.result(30)
+
+        client = threading.Thread(target=bursts)
+        client.start()
+        try:
+            deadline = time.perf_counter() + 30
+            while eng.stats("a")["n_batches"] < 64 and time.perf_counter() < deadline:
+                time.sleep(0.01)
+            t0 = time.perf_counter()
+            s0 = eng.stats("a")
+            time.sleep(2.0)
+            s1 = eng.stats("a")
+            t1 = time.perf_counter()
+        finally:
+            stop.set()
+            client.join(30)
+    assert not client.is_alive()
+    window = t1 - t0
+    for a, b in zip(s0["shards"], s1["shards"]):
+        assert b["n_batches"] - a["n_batches"] > 10
+        covered = sum(
+            b["per_stage"][k]["total_ms"] - a["per_stage"][k]["total_ms"] for k in per_batch
+        ) * 1e-3
+        assert abs(covered - window) <= max(0.02 * window, 2e-3), (covered, window)
+
+
+def test_ended_shard_threads_report_not_alive(designs):
+    """A dispatcher's stop event must not shadow ``Thread._stop``: joining
+    or asking ``is_alive()`` of an ended shard used to raise TypeError."""
+    eng = ServeEngine(config=ServeConfig(max_batch=8, shards=2))
+    eng.register("a", designs["a"])
+    shards = list(eng._runners["a"].shards)
+    eng.infer("a", _samples(1)[0])
+    eng.shutdown()
+    for sh in shards:
+        sh.join(5)
+        assert not sh.is_alive()
