@@ -207,11 +207,12 @@ class CompileConfig(_ConfigBase):
     strategy             "da" (CMVM solver) or "latency" (per-output CSD
                          trees, the hls4ml latency-strategy baseline).
     max_delay_per_stage  pipelining budget per register stage.
-    use_pallas           execute CMVMs through the Pallas adder-graph
-                         kernel instead of the jnp gather executor
-                         (CPU backend only, in interpret mode: the
-                         kernel does not lower for the TPU, so
-                         ``build_steps`` raises there).
+    use_pallas           execute every CMVM through the Pallas adder-graph
+                         kernel instead of the default executors (one
+                         exact MXU dot where ``build_steps`` proves it,
+                         else the jnp adder graph); CPU backend only, in
+                         interpret mode: the kernel does not lower for
+                         the TPU, so ``build_steps`` raises there.
     jobs                 solver thread-pool width (None = cpu_count,
                          1 = in-line serial); never changes the bits —
                          serial fallbacks are recorded loudly in

@@ -135,8 +135,8 @@ def adder_graph_apply(
     x: int array [..., n_inputs] (integer grid). Returns int32
     [..., n_outputs]. ``use_pallas`` selects the Pallas kernel (run in
     interpret mode on the CPU backend, see ``resolve_interpret``); the
-    default is the pure-jnp reference, which XLA fuses well on any
-    backend.
+    default is the pure-jnp reference.  ``build_steps`` serves a step
+    through here only where ``dot.dot_matrix`` refuses the exact dot.
     """
     from .kernel import adder_graph_pallas
     from .ref import adder_graph_ref

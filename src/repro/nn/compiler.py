@@ -40,6 +40,7 @@ from ..core.solver import (
 )
 from ..flow.config import UNSET, CompileConfig, SolverConfig, resolve_legacy
 from ..kernels.adder_graph import adder_graph_apply, compile_tables
+from ..kernels.adder_graph.dot import cmvm_dot, dot_matrix
 from ..obs import trace
 from .layers import (
     AvgPool2D,
@@ -180,16 +181,45 @@ class CompiledDesign:
         exps = np.array([q_.exp if not q_.is_zero else 0 for q_ in self.out_qints])
         return y.astype(jnp.float32) * (2.0 ** exps).reshape(self.out_shape)
 
+    def _cmvm_steps(self) -> list[tuple[str, int]]:
+        """(executor, MACs an event) of each CMVM step, in pipeline order."""
+        mats = _dot_matrices(self.tables, self.programs, self.use_pallas)
+        return [
+            (
+                "adder_graph" if mats[s.table] is None else "dot",
+                n * self.tables[s.table].n_inputs * self.tables[s.table].n_outputs,
+            )
+            for s, n in _cmvm_instances(
+                self.step_specs, self.tables, int(np.prod(self.in_shape))
+            )
+        ]
+
+    @property
+    def executors(self) -> list[str]:
+        """Each CMVM step's executor, in pipeline order: "dot" (one exact
+        MXU dot, see ``build_steps``) or "adder_graph"."""
+        return [ex for ex, _ in self._cmvm_steps()]
+
+    @property
+    def dot_share(self) -> float:
+        """MACs of the CMVM steps that run as a dot over all CMVM MACs
+        (1.0 for a design with no CMVM step)."""
+        steps = self._cmvm_steps()
+        total = sum(macs for _, macs in steps)
+        return sum(macs for ex, macs in steps if ex == "dot") / total if total else 1.0
+
     def summary(self) -> str:
         hdr = (
             f"{'layer':<20}{'shape':<14}{'adders':>8}{'LUTbits':>9}{'depth':>7}"
-            f"{'stages':>7}{'FFbits':>8}{'t[s]':>8}"
+            f"{'stages':>7}{'FFbits':>8}{'t[s]':>8}{'exec':>13}"
         )
         rows = [hdr, "-" * len(hdr)]
-        for r in self.reports:
+        executors = self.executors
+        for k, r in enumerate(self.reports):
+            ex = executors[k] if k < len(executors) else "?"
             rows.append(
                 f"{r.name:<20}{r.shape:<14}{r.adders:>8}{r.cost_bits:>9}{r.depth:>7}"
-                f"{r.stages:>7}{r.ff_bits:>8}{r.solver_time_s:>8.2f}"
+                f"{r.stages:>7}{r.ff_bits:>8}{r.solver_time_s:>8.2f}{ex:>13}"
             )
         rows.append("-" * len(hdr))
         rows.append(
@@ -202,12 +232,20 @@ class CompiledDesign:
 # ----------------------------------------------------------------------
 # Step builder: StepSpec -> executable jnp callable
 # ----------------------------------------------------------------------
-def build_steps(specs: list[StepSpec], tables: list, use_pallas: bool = False):
+def build_steps(
+    specs: list[StepSpec], tables: list, use_pallas: bool = False, programs: list | None = None
+):
     """Construct the executable pipeline from declarative step specs.
 
-    ``tables``: the design's per-unique-CMVM ``AdderGraphTables`` list.
-    Both ``compile_model`` and the artifact loader go through this
-    single builder, which is what makes save->load bit-exact.
+    ``tables``: the design's per-unique-CMVM ``AdderGraphTables`` list;
+    ``programs``: its packed DAIS programs, one per table.  Both
+    ``compile_model`` and the artifact loader go through this single
+    builder, which is what makes save->load bit-exact.
+
+    A CMVM step whose table ``repro.kernels.adder_graph.dot.dot_matrix``
+    proves exact runs as one bfloat16 MXU dot of the matrix its adder
+    graph computes; every other step (no program for its table, a refused
+    proof, or ``use_pallas=True``) runs the adder graph itself.
 
     ``use_pallas=True`` runs only on the CPU backend (interpret mode):
     the adder-graph Pallas kernel does not lower through Mosaic, so any
@@ -217,13 +255,40 @@ def build_steps(specs: list[StepSpec], tables: list, use_pallas: bool = False):
         raise NotImplementedError(
             "the adder-graph Pallas kernel (repro.kernels.adder_graph) does not "
             f"compile for the {jax.default_backend()!r} backend; build the design "
-            "with use_pallas=False to run the jnp executor"
+            "with use_pallas=False to run the default executors"
         )
-    return [_build_step(s, tables, use_pallas) for s in specs]
+    mats = _dot_matrices(tables, programs, use_pallas)
+    return [_build_step(s, tables, mats, use_pallas) for s in specs]
 
 
-def _build_cmvm_fn(spec: StepSpec, tables: list, use_pallas: bool):
-    tab = tables[spec.table]
+def _dot_matrices(tables: list, programs: list | None, use_pallas: bool) -> list:
+    """Per table: the integer matrix of its exact dot, or None."""
+    if use_pallas or not programs:
+        return [None] * len(tables)
+    return [dot_matrix(t, p) for t, p in zip(tables, programs, strict=True)]
+
+
+def _cmvm_instances(specs: list[StepSpec], tables: list, width: int, out=None) -> list:
+    """(spec, instances an event) of each CMVM step, in pipeline order,
+    for a pipeline fed ``width`` values an event."""
+    out = [] if out is None else out
+    for s in specs:
+        p = s.params
+        if s.kind in ("dense", "conv"):
+            n = width // p["d_in"] if s.kind == "dense" else p["oh"] * p["ow"]
+            out.append((s, n))
+            width = n * tables[s.table].n_outputs
+        elif s.kind in ("maxpool", "avgpool"):
+            width //= p["ph"] * p["pw"]
+        elif s.kind == "residual":
+            _cmvm_instances(s.body or [], tables, width, out)
+    return out
+
+
+def _build_cmvm_fn(spec: StepSpec, tables: list, mats: list, use_pallas: bool):
+    tab, mat = tables[spec.table], mats[spec.table]
+    if mat is not None:
+        mat = jnp.asarray(mat, jnp.bfloat16)
     bias = (
         jnp.asarray(spec.arrays["bias"], jnp.int32) if "bias" in spec.arrays else None
     )
@@ -233,8 +298,11 @@ def _build_cmvm_fn(spec: StepSpec, tables: list, use_pallas: bool):
         else None
     )
 
-    def cmvm(v, tab=tab, bias=bias, shift=shift, use_pallas=use_pallas):
-        y = adder_graph_apply(tab, v, use_pallas=use_pallas)
+    def cmvm(v, tab=tab, mat=mat, bias=bias, shift=shift, use_pallas=use_pallas):
+        if mat is None:
+            y = adder_graph_apply(tab, v, use_pallas=use_pallas)
+        else:
+            y = cmvm_dot(mat, v)
         if shift is not None:
             y = y << shift
         return y + bias if bias is not None else y
@@ -242,18 +310,21 @@ def _build_cmvm_fn(spec: StepSpec, tables: list, use_pallas: bool):
     return cmvm
 
 
-def _build_step(spec: StepSpec, tables: list, use_pallas: bool) -> Callable:
+def _build_step(spec: StepSpec, tables: list, mats: list, use_pallas: bool) -> Callable:
     kind, p = spec.kind, spec.params
     if kind == "dense":
-        f = _build_cmvm_fn(spec, tables, use_pallas)
+        f = _build_cmvm_fn(spec, tables, mats, use_pallas)
 
+        # the CMVM sees the [B, instances, d_in] view: the dot contracts
+        # its last axis in place, where a flat [B * instances, d_in]
+        # operand pads a narrow d_in out to the TPU's 128 lanes
         def step(v, d_in=p["d_in"], f=f):
             n = v.shape[0]
-            return f(v.reshape(-1, d_in)).reshape(n, -1)
+            return f(v.reshape(n, -1, d_in)).reshape(n, -1)
 
         return step
     if kind == "conv":
-        f = _build_cmvm_fn(spec, tables, use_pallas)
+        f = _build_cmvm_fn(spec, tables, mats, use_pallas)
         h, w, cin = p["h"], p["w"], p["cin"]
         kh, kw, sh, sw = p["kh"], p["kw"], p["sh"], p["sw"]
         oh, ow = p["oh"], p["ow"]
@@ -266,7 +337,7 @@ def _build_step(spec: StepSpec, tables: list, use_pallas: bool) -> Callable:
                 for dx in range(kw)
             ]
             cols = jnp.concatenate(patches, axis=-1)  # [B, oh, ow, kh*kw*cin]
-            y = f(cols.reshape(-1, kh * kw * cin))
+            y = f(cols.reshape(-1, oh * ow, kh * kw * cin))
             return y.reshape(-1, oh * ow * y.shape[-1])
 
         return step
@@ -300,7 +371,7 @@ def _build_step(spec: StepSpec, tables: list, use_pallas: bool) -> Callable:
 
         return step
     if kind == "residual":
-        body = tuple(_build_step(s, tables, use_pallas) for s in spec.body or [])
+        body = tuple(_build_step(s, tables, mats, use_pallas) for s in spec.body or [])
         sa = jnp.asarray(np.asarray(spec.arrays["sa"])[None, :], jnp.int32)
         sb = jnp.asarray(np.asarray(spec.arrays["sb"])[None, :], jnp.int32)
 
@@ -743,7 +814,7 @@ def _compile_model(
     design.solver_stats["n_program_packs"] = n_packs
     design.solver_stats["n_program_arrays_reused"] = n_reused
     design.step_specs = specs
-    design.steps = build_steps(specs, design.tables, cfg.use_pallas)
+    design.steps = build_steps(specs, design.tables, cfg.use_pallas, design.programs)
     design.out_shape = shape
     design.out_qints = qints
     _stitch_span.__exit__(None, None, None)

@@ -3,7 +3,8 @@
 This is a bit-exact mirror of the jnp executor built by
 :func:`repro.nn.compiler.build_steps` — same step kinds, same int32
 arithmetic, same shift/clip/sum semantics — expressed entirely in numpy.
-The serve engine's circuit breaker routes batches here when
+CMVM steps run their adder graph here, also where ``build_steps`` runs
+them as the exact MXU dot, which returns the same bits.  The serve engine's circuit breaker routes batches here when
 ``ServeConfig.fallback="interpreter"`` and the jit path is tripped:
 correctness survives a poisoned jit cache at reduced throughput, and
 the fallback shares no jax machinery with the failing path.

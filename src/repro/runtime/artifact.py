@@ -376,7 +376,7 @@ def _rebuild(
         use_pallas=use_pallas,
         config=config,
     )
-    design.steps = build_steps(specs, tables, use_pallas)
+    design.steps = build_steps(specs, tables, use_pallas, programs)
     design.solver_stats = {
         "n_solves": 0,
         "n_cache_hits": 0,

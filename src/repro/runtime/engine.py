@@ -1049,6 +1049,7 @@ class ServeEngine:
             with self._lock:
                 self._runners.pop(name, None)
             raise
+        get_registry().set_gauge("serve_cmvm_dot_share", design.dot_share, model=name)
         return design
 
     def unregister(self, name: str, timeout: float = 5.0) -> None:
