@@ -33,7 +33,7 @@ def readings(workload: str, seed: int, seconds: float, calls: int, root: Path = 
 
     cell = load_cell(workload, root)
     rng = runner.rng_of(seed)
-    params = network.make_params(cell.config)
+    params = network.make_params(cell.config, root)
     if cell.traffic["kind"] == "open_poisson":
         pool, _due, pick, _, _ = runner.draw_online(cell, seconds, rng)
         rows = [pick]
@@ -41,10 +41,10 @@ def readings(workload: str, seed: int, seconds: float, calls: int, root: Path = 
         pool, offsets = runner.draw_bulk(cell, rng)
         chunk = int(cell.traffic["chunk_events"])
         rows = [np.arange(o, o + chunk) for o in offsets[:calls]]
-    want = reference.forward(cell.config, params, pool)
+    want = reference.forward(cell.config, params, pool, root=root)
     out = {"workload": workload, "seed": seed, "answers": int(sum(len(r) for r in rows))}
     for precision in ("float32", "bfloat16"):
-        got = reference.forward(cell.config, params, pool, precision)
+        got = reference.forward(cell.config, params, pool, precision, root)
         mism = sum(check.mismatched(got[r], 1.0, want[r]) for r in rows)
         numbers = {"mismatched": mism, "failed": 0}
         out[precision] = {"mismatched": mism, "correct": check.verdict(numbers)}

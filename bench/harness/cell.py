@@ -3,8 +3,11 @@
 A cell names a configuration (``bench/configs/<config>.json``) and a
 traffic mix (``bench/traffic/<traffic>.json``); its metrics are the
 entries of ``end_to_end`` and ``per_layer`` that apply to it, and each
-per-layer metric is read by ``bench/metrics/<metric>.py``.  Adding any
-of these is a new file and a new entry, never an edit.
+per-layer metric is read by ``bench/metrics/<metric>.py``.  Each kind of
+layer a configuration's ``layers`` name is ``bench/layers/<kind>.py``
+(``kinds.py``).  Adding any of these is a new file and a new entry,
+never an edit.  All of them are read from the checkout the run was
+given, ``root``.
 """
 
 from __future__ import annotations
@@ -24,6 +27,7 @@ class Cell:
     traffic: dict
     end_to_end: tuple  # metric entries this cell reports with --trace 0
     per_layer: tuple  # metric entries this cell reports with --trace 1
+    root: Path  # the checkout its files are read from
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -46,4 +50,4 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         for m in bench["per_layer"]
         if (name in m["workloads"] if "workloads" in m else m["moves"] in reported)
     )
-    return Cell(name, int(w["chips"]), config, traffic, e2e, per_layer)
+    return Cell(name, int(w["chips"]), config, traffic, e2e, per_layer, Path(root))

@@ -2,10 +2,12 @@
 
 The weights are drawn here, in numpy, from the configuration's
 ``weights.seed``: the program compiles them, and the reference reads the
-same float arrays and quantizes them itself.  The compiled design is
-kept as an artifact under ``bench/.cache/designs/``, keyed by a digest
-of the configuration, so that only the first run in a checkout solves;
-later runs load it with zero solver calls.
+same float arrays and quantizes them itself.  What each layer draws, and
+how a program's layer spec reads in the configuration's vocabulary, is
+its kind's file under ``bench/layers/`` (``kinds.py``).  The compiled
+design is kept as an artifact under ``bench/.cache/designs/``, keyed by a
+digest of the configuration, so that only the first run in a checkout
+solves; later runs load it with zero solver calls.
 """
 
 from __future__ import annotations
@@ -17,78 +19,61 @@ from pathlib import Path
 
 import numpy as np
 
-_KIND_OF_CLASS = {
-    "QDense": "dense",
-    "QDenseOnAxis": "dense_on_axis",
-    "ReLU": "relu",
-    "Flatten": "flatten",
-    "Residual": "residual",
-}
+from . import kinds
+from .cell import ROOT
 
 
-def _quant_dict(q) -> dict | None:
+def quant_dict(q) -> dict | None:
+    """A program's ``QuantConfig`` in the configuration's vocabulary."""
     if q is None:
         return None
     return {"bits": int(q.bits), "int_bits": int(q.int_bits), "signed": bool(q.signed)}
 
 
-def _make_layer_params(layers: list, shape: tuple, rng, wcfg: dict) -> tuple[list, tuple]:
+def glorot(rng, wcfg: dict, shape: tuple) -> dict:
+    """Glorot-uniform float32 weights of ``shape``, whose fan-in is the
+    product of all but its last axis, then one uniform bias per output."""
+    lim = (3.0 / int(np.prod(shape[:-1]))) ** 0.5
+    w = rng.uniform(-lim, lim, size=shape).astype(np.float32)
+    b = rng.uniform(-wcfg["b_uniform"], wcfg["b_uniform"], size=shape[-1])
+    return {"w": w, "b": b.astype(np.float32)}
+
+
+def _make_layer_params(layers: list, shape: tuple, rng, wcfg: dict, root: Path):
+    def seq(body, shape):
+        return _make_layer_params(body, shape, rng, wcfg, root)
+
     params: list = []
     for layer in layers:
-        kind = layer["kind"]
-        if kind in ("dense", "dense_on_axis"):
-            ax = layer["axis"] if kind == "dense_on_axis" else len(shape) - 1
-            fan_in, units = shape[ax], layer["units"]
-            lim = (3.0 / fan_in) ** 0.5
-            w = rng.uniform(-lim, lim, size=(fan_in, units)).astype(np.float32)
-            b = rng.uniform(-wcfg["b_uniform"], wcfg["b_uniform"], size=units)
-            params.append({"w": w, "b": b.astype(np.float32)})
-            shape = tuple(units if i == ax else s for i, s in enumerate(shape))
-        elif kind == "flatten":
-            params.append({})
-            shape = (int(np.prod(shape)),)
-        elif kind == "relu":
-            params.append({})
-        elif kind == "residual":
-            body, _ = _make_layer_params(layer["body"], shape, rng, wcfg)
-            params.append({"body": body})
-        else:
-            raise ValueError(f"unknown layer kind {kind!r}")
+        p, shape = kinds.kind(layer["kind"], root).init(layer, shape, rng, wcfg, seq)
+        params.append(p)
     return params, shape
 
 
-def make_params(config: dict) -> list:
+def make_params(config: dict, root: Path = ROOT) -> list:
     """Float32 weights and biases for every layer of the configuration,
     from ``config["weights"]["seed"]``; nested the way the layers are."""
     wcfg = config["weights"]
     if wcfg["w"] != "glorot_uniform":
         raise ValueError(f"unknown weight init {wcfg['w']!r}")
     rng = np.random.default_rng(int(wcfg["seed"]))
-    return _make_layer_params(config["layers"], tuple(config["in_shape"]), rng, wcfg)[0]
+    return _make_layer_params(config["layers"], tuple(config["in_shape"]), rng, wcfg, root)[0]
 
 
-def _describe_program(model) -> list:
+def _describe_program(model, root: Path) -> list:
     """The program's layer specs in the configuration's layer vocabulary."""
+
+    def seq(body):
+        return _describe_program(body, root)
+
     out = []
     for spec in model:
-        cls = type(spec).__name__
-        if cls not in _KIND_OF_CLASS:
-            raise ValueError(f"the configuration format has no layer for {cls}")
-        d: dict = {"kind": _KIND_OF_CLASS[cls]}
-        if hasattr(spec, "units"):
-            d["units"] = int(spec.units)
-            d["w_quant"] = _quant_dict(spec.w_quant)
-        if hasattr(spec, "axis"):
-            d["axis"] = int(spec.axis)
-        if getattr(spec, "out_quant", None) is not None:
-            d["out_quant"] = _quant_dict(spec.out_quant)
-        if cls == "Residual":
-            d["body"] = _describe_program(spec.body)
-        out.append(d)
+        name, mod = kinds.of_program(type(spec).__name__, root)
+        out.append({"kind": name, **mod.describe(spec, seq)})
     return out
 
 
-def program_model(config: dict):
+def program_model(config: dict, root: Path = ROOT):
     """The program's own model for this configuration, from
     ``repro.nn.models.<function>``; refuses one that is not the network
     the configuration states."""
@@ -98,8 +83,8 @@ def program_model(config: dict):
     model, in_shape, in_quant = getattr(models, prog["function"])(**prog["kwargs"])
     got = {
         "in_shape": list(in_shape),
-        "in_quant": _quant_dict(in_quant),
-        "layers": _describe_program(model),
+        "in_quant": quant_dict(in_quant),
+        "layers": _describe_program(model, root),
     }
     want = {k: config[k] for k in got}
     if json.loads(json.dumps(got)) != want:
@@ -128,9 +113,9 @@ def load_design(config: dict, root: Path):
             return Flow.load(path), False
         except ArtifactCorruptError:
             shutil.rmtree(path)
-    model, in_shape, in_quant = program_model(config)
+    model, in_shape, in_quant = program_model(config, root)
     design = Flow.compile(
-        model, make_params(config), in_shape, in_quant, config=CompileConfig(verify="cheap")
+        model, make_params(config, root), in_shape, in_quant, config=CompileConfig(verify="cheap")
     )
     design.save(path)
     return Flow.load(path), True

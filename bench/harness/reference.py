@@ -2,12 +2,15 @@
 
 It reads the network from the configuration file (``layers``) and the
 float weights the benchmark drew for it, and computes the real-valued
-output of the fixed-point network those describe: weights rounded to
-the nearest point of their grid, biases rounded onto the accumulator
-grid (input step times weight step, 24 bits), ReLU outputs floored and
-saturated onto the activation grid, residual sums and flattening
-exact.  These are the semantics of HGQ-style quantized networks that
-da4ml compiles.  It imports nothing of the program under test.
+output of the fixed-point network those describe.  Each layer's
+semantics is its kind's ``forward`` under ``bench/layers/``
+(``kinds.py``); the numerics they share are here: a fixed-point grid,
+rounding onto it, the matrix product in each precision, and the affine
+map whose weights are rounded to the nearest point of their grid and
+whose biases are rounded onto the accumulator grid (input step times
+weight step, 24 bits).  These are the semantics of HGQ-style quantized
+networks that da4ml compiles.  It imports nothing of the program under
+test.
 
 ``precision="float64"`` is exact here: every value is a dyadic
 rational far inside float64's 53-bit significand.  The lower precisions
@@ -19,8 +22,13 @@ accumulation, as a matrix unit computing in bfloat16 would.
 
 from __future__ import annotations
 
+from pathlib import Path
+
 import ml_dtypes
 import numpy as np
+
+from . import kinds
+from .cell import ROOT
 
 PRECISIONS = ("float64", "float32", "bfloat16")
 BIAS_BITS = 24
@@ -35,14 +43,15 @@ def grid(q: dict) -> tuple[int, int, int]:
     return lo, (1 << mag) - 1, int_bits - bits
 
 
-def _on_grid(x: np.ndarray, q: dict, rounding: str) -> np.ndarray:
+def on_grid(x: np.ndarray, q: dict, rounding: str) -> np.ndarray:
+    """``x`` rounded (``"floor"`` or ``"round"``) onto grid ``q``, saturated."""
     lo, hi, exp = grid(q)
     step = 2.0**exp
     k = np.floor(x / step) if rounding == "floor" else np.round(x / step)
     return np.clip(k, lo, hi) * step
 
 
-def _matmul(x: np.ndarray, w: np.ndarray, precision: str) -> np.ndarray:
+def matmul(x: np.ndarray, w: np.ndarray, precision: str) -> np.ndarray:
     if precision == "float64":
         return x @ w
     if precision == "float32":
@@ -53,43 +62,40 @@ def _matmul(x: np.ndarray, w: np.ndarray, precision: str) -> np.ndarray:
     return (xb @ wb).astype(bf).astype(np.float64)
 
 
-def _dense(x, p, layer, cur, precision, axis=None):
-    w = _on_grid(np.asarray(p["w"], np.float64), layer["w_quant"], "round")
-    if axis is not None:
-        x = np.moveaxis(x, axis, -1)
-    y = _matmul(x, w, precision)
+def affine(x, p: dict, w_quant: dict, cur: dict | None, precision: str) -> np.ndarray:
+    """``x @ w + b`` over the last axis of ``x``, with ``w`` [d_in, units]
+    rounded onto ``w_quant`` and ``b`` onto the accumulator grid of the
+    input grid ``cur``."""
+    w = on_grid(np.asarray(p["w"], np.float64), w_quant, "round")
+    y = matmul(x, w, precision)
     if "b" in p:
         if cur is None:
             raise ValueError("a biased layer needs its input on a known grid")
-        exp = grid(layer["w_quant"])[2] + grid(cur)[2]
+        exp = grid(w_quant)[2] + grid(cur)[2]
         bias_q = {"bits": BIAS_BITS, "int_bits": BIAS_BITS + exp, "signed": True}
-        y = y + _on_grid(np.asarray(p["b"], np.float64), bias_q, "round")
-    if axis is not None:
-        y = np.moveaxis(y, -1, axis)
+        y = y + on_grid(np.asarray(p["b"], np.float64), bias_q, "round")
     return y
 
 
-def _run(layers, params, x, cur, precision):
+def requantize(y: np.ndarray, layer: dict) -> tuple[np.ndarray, dict | None]:
+    """A weighted layer's outputs and their grid: floored and saturated
+    onto its ``out_quant`` where it has one, else on no single grid."""
+    if "out_quant" in layer:
+        return on_grid(y, layer["out_quant"], "floor"), layer["out_quant"]
+    return y, None
+
+
+def _run(layers, params, x, cur, precision, root):
+    def seq(body, body_params, x, cur):
+        return _run(body, body_params, x, cur, precision, root)
+
     for layer, p in zip(layers, params):
-        kind = layer["kind"]
-        if kind == "dense":
-            x, cur = _dense(x, p, layer, cur, precision), None
-        elif kind == "dense_on_axis":
-            x, cur = _dense(x, p, layer, cur, precision, axis=layer["axis"] + 1), None
-        elif kind == "relu":
-            x = np.maximum(x, 0.0)
-            if "out_quant" in layer:
-                x, cur = _on_grid(x, layer["out_quant"], "floor"), layer["out_quant"]
-        elif kind == "flatten":
-            x = x.reshape(x.shape[0], -1)
-        elif kind == "residual":
-            x, cur = x + _run(layer["body"], p["body"], x, cur, precision), None
-        else:
-            raise ValueError(f"reference has no layer kind {kind!r}")
-    return x
+        x, cur = kinds.kind(layer["kind"], root).forward(x, p, layer, cur, precision, seq)
+    return x, cur
 
 
-def forward(config: dict, params: list, x_int: np.ndarray, precision: str = "float64"):
+def forward(config: dict, params: list, x_int: np.ndarray, precision: str = "float64",
+            root: Path = ROOT):
     """Real-valued outputs [n, *out_shape] (float64) for integer events
     ``x_int`` [n, *in_shape] on the configuration's input grid, computed
     in blocks of ``BLOCK_EVENTS`` events."""
@@ -99,5 +105,5 @@ def forward(config: dict, params: list, x_int: np.ndarray, precision: str = "flo
     out = []
     for i in range(0, len(x_int), BLOCK_EVENTS):
         x = np.asarray(x_int[i : i + BLOCK_EVENTS], np.float64) * step
-        out.append(_run(config["layers"], params, x, config["in_quant"], precision))
+        out.append(_run(config["layers"], params, x, config["in_quant"], precision, root)[0])
     return np.concatenate(out)

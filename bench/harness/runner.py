@@ -45,6 +45,7 @@ class Record:
     batches: int = 0  # online: batches the engine dispatched
     stage_s: dict | None = None  # online: engine stage -> (seconds, count)
     lag_s: np.ndarray | None = None  # online: send time minus due time
+    latency_s: np.ndarray | None = None  # online: due time to answer, a failure the whole run
     trace: Summary | None = None  # --trace 1 only
 
     def peak(self) -> dict:
@@ -135,6 +136,8 @@ def _online(cell, design, rng, seconds, capture):
         "batches": s1["n_batches"] - s0["n_batches"],
         "stage_s": _stage_delta(s0["per_stage"], s1["per_stage"]),
         "lag_s": run.lag_s,
+        # a failed request missed every limit: it counts as waiting the whole run
+        "latency_s": np.minimum(run.latency_s, run.seconds),
     }
     fallback = s1["n_fallback_batches"] - s0["n_fallback_batches"]
     if fallback:
@@ -212,15 +215,15 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, root: Path = ROO
     scale = check.output_scale(design)
     del design
     gc.collect()
-    want = reference.forward(cell.config, network.make_params(cell.config), pool)
+    want = reference.forward(
+        cell.config, network.make_params(cell.config, root), pool, root=root
+    )
     if kind == "open_poisson":
         ok = ~run.failed & ~np.isnan(run.done)
         attempted = len(run.due)
         failed = attempted - int(ok.sum())
         mism = check.mismatched(run.outputs[ok], scale, want[pick[ok]])
-        # a failed request missed every limit: it counts as waiting the whole run
-        lat_ms = np.minimum(run.latency_s, run.seconds) * 1e3
-        e2e = {"p95_ms": float(np.percentile(lat_ms, 95)), "p50_ms": float(np.percentile(lat_ms, 50))}
+        e2e = {"p50_ms": float(np.percentile(fields["latency_s"], 50)) * 1e3}
     else:
         attempted, failed = run.events, 0
         mism = sum(

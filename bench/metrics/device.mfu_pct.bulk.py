@@ -9,5 +9,5 @@ def read(rec):
     t = rec.trace
     if t is None or not t.n_devices or rec.seconds <= 0:
         return None
-    rate = work.ops_per_event(rec.cell.config) * rec.events / rec.seconds
+    rate = work.ops_per_event(rec.cell.config, rec.cell.root) * rec.events / rec.seconds
     return rate / rec.peak()["int8_ops_per_s"] * 100.0

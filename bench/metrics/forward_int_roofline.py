@@ -12,5 +12,7 @@ def read(rec):
     t = rec.trace
     if t is None or not t.n_devices or not t.module_n.get(MODULE):
         return None
-    least, _bound = work.least_time_s(rec.cell.config, rec.chunk, rec.in_itemsize, rec.peak())
+    least, _bound = work.least_time_s(
+        rec.cell.config, rec.chunk, rec.in_itemsize, rec.peak(), rec.cell.root
+    )
     return least * t.module_n[MODULE] / t.module_s[MODULE] * 100.0

@@ -1,9 +1,11 @@
-"""The engine's stage readers on fabricated records: bookkeeping time per
-batch and the dispatcher's busy share, and no reading where the engine
-does not count the stages they read."""
+"""The engine's stage readers and the client's latency readers on
+fabricated records: bookkeeping time per batch, the dispatcher's busy
+share, the request tail and the generator's lag, and no reading where
+the record does not hold what they read."""
 
 import dataclasses
 
+import numpy as np
 import pytest
 
 from bench.harness import runner
@@ -48,3 +50,13 @@ def test_engine_stage_readers(name, shards, want):
 @pytest.mark.parametrize("stage_s", [OLD_STAGES, None], ids=["without_the_stage", "bulk"])
 def test_engine_stage_readers_without_the_stage(name, stage_s):
     assert _read(name, _record(stage_s)) is None
+
+
+def test_client_readers_take_every_request_of_the_window():
+    # 95 requests at 1 ms and 5 at 1 s: the 95th percentile lies between them
+    lat = np.r_[np.full(95, 1e-3), np.full(5, 1.0)]
+    rec = dataclasses.replace(_record(STAGES), latency_s=lat, lag_s=lat)
+    assert _read("client.p95_ms.online", rec) == pytest.approx(np.percentile(lat, 95) * 1e3)
+    assert _read("client.lag_p99_ms.online", rec) == pytest.approx(np.percentile(lat, 99) * 1e3)
+    for name in ("client.p95_ms.online", "client.lag_p99_ms.online"):
+        assert _read(name, _record(None)) is None

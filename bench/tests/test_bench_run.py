@@ -1,7 +1,8 @@
 """Whole runs of the benchmark on the CPU, with the look for a chip skipped:
 the result line's shape, the comparison with the reference, faults planted
-in the timed path, a cell added with data files alone, and the command's
-refusal to report anything without a TPU."""
+in the timed path, a cell added with data files alone, layer kinds read
+from the checkout, and the command's refusal to report anything without a
+TPU."""
 
 import json
 import os
@@ -17,6 +18,7 @@ from bench import control
 from bench.harness import check, network, reference, runner
 
 ROOT = Path(__file__).resolve().parents[2]
+DATA = Path(__file__).resolve().parent / "data"
 SEED = 2**31 + 12345  # seeds past 32 signed bits must work
 
 
@@ -25,7 +27,7 @@ def root(tmp_path_factory):
     """A checkout holding the benchmark's data files, plus one cell added
     by data alone: a small-chunk bulk mix and a per-layer metric."""
     r = tmp_path_factory.mktemp("checkout")
-    for d in ("configs", "traffic", "metrics"):
+    for d in ("configs", "traffic", "metrics", "layers"):
         shutil.copytree(ROOT / "bench" / d, r / "bench" / d)
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     (r / "bench" / "traffic" / "chunks_256.json").write_text(
@@ -41,7 +43,7 @@ def root(tmp_path_factory):
             m["workloads"].append("jet_tagger.bulk_small")
     spec["per_layer"].append({"name": "engine.batches.online", "unit": "batches",
                               "better": "higher", "source": "program_counter",
-                              "layer": "engine", "moves": "p95_ms",
+                              "layer": "engine", "moves": "p50_ms",
                               "workloads": ["jet_tagger.online"]})
     (r / "BENCHMARK.json").write_text(json.dumps(spec))
     return r
@@ -63,7 +65,7 @@ def _shape(line):
 
 @pytest.mark.parametrize(
     ("cell", "e2e"),
-    [("jet_tagger.online", {"p95_ms", "p50_ms", "setup_s"}),
+    [("jet_tagger.online", {"p50_ms", "setup_s"}),
      ("jet_tagger.bulk_small", {"events_per_s", "setup_s"})],
 )
 def test_rehearsal_end_to_end_line(root, cell, e2e):
@@ -79,7 +81,7 @@ def test_rehearsal_traced_line_has_no_device_numbers_off_a_tpu(root):
     _shape(online)
     # host-side layers are read; nothing of the device is, and no idle share
     assert set(online["metrics"]) == {
-        "client.lag_p99_ms.online", "engine.queue_wait_us.online",
+        "client.p95_ms.online", "client.lag_p99_ms.online", "engine.queue_wait_us.online",
         "engine.dispatch_us.online", "engine.batches.online",
     }
     assert "busy_s" not in online["device"] and "breakdown" not in online
@@ -104,6 +106,29 @@ def test_an_answer_altered_where_it_is_produced_is_not_correct(root, cell, monke
     line = _run(root, cell)
     assert line["correct"] is False
     assert line["checks"]["mismatched"]["value"] >= 1
+
+
+def test_a_kind_file_altered_in_the_checkout_is_not_correct(root, tmp_path):
+    """The reference reads each layer kind from the checkout it is given:
+    a ``relu.py`` there whose outputs lie one grid step high fails the
+    run, with no module of the harness edited."""
+    altered = tmp_path / "checkout"
+    shutil.copytree(root, altered)
+    relu = altered / "bench" / "layers" / "relu.py"
+    relu.write_text(relu.read_text() + """
+
+from bench.harness.reference import grid
+
+_forward = forward
+
+
+def forward(x, p, layer, cur, precision, seq):
+    x, cur = _forward(x, p, layer, cur, precision, seq)
+    return x + 2.0 ** grid(cur)[2], cur
+""")
+    line = _run(altered, "jet_tagger.bulk_small")
+    assert line["correct"] is False
+    assert line["checks"]["mismatched"]["value"] > 0
 
 
 def test_failed_requests_are_not_correct(root, monkeypatch):
@@ -163,16 +188,26 @@ def test_reference_matches_the_program_and_its_control_does_not(root, name):
     x = runner.events(cfg, 256, np.random.default_rng(7))
     assert x.dtype == np.int8
     y = np.asarray(design.forward_int(x))
-    params = network.make_params(cfg)
+    params = network.make_params(cfg, root)
     scale = check.output_scale(design)
-    assert check.mismatched(y, scale, reference.forward(cfg, params, x)) == 0
-    assert check.mismatched(y, scale, reference.forward(cfg, params, x, "float32")) == 0
-    assert check.mismatched(y, scale, reference.forward(cfg, params, x, "bfloat16")) > 0
+
+    def want(precision):
+        return reference.forward(cfg, params, x, precision, root)
+
+    assert check.mismatched(y, scale, want("float64")) == 0
+    assert check.mismatched(y, scale, want("float32")) == 0
+    assert check.mismatched(y, scale, want("bfloat16")) > 0
 
 
-def test_program_model_must_be_the_configured_network():
-    cfg = json.loads((ROOT / "bench" / "configs" / "jet_tagger.json").read_text())
-    cfg["layers"][0]["units"] = 63
+@pytest.mark.parametrize(
+    ("path", "layer", "key", "value"),
+    [(ROOT / "bench" / "configs" / "jet_tagger.json", 0, "units", 63),
+     (DATA / "svhn_cnn_30.json", 3, "filters", 17)],
+    ids=["jet_tagger", "svhn_cnn_30"],
+)
+def test_program_model_must_be_the_configured_network(path, layer, key, value):
+    cfg = json.loads(path.read_text())
+    cfg["layers"][layer][key] = value
     with pytest.raises(ValueError, match="does not build the network"):
         network.program_model(cfg)
 
