@@ -2,8 +2,9 @@
 
     python chip_smoke.py
 
-For ``jet_tagger``, ``svhn_cnn`` and full-size ``mlp_mixer_jet`` (the
-widths of ``repro/nn/models.py``), with weights made from a fixed seed:
+For ``jet_tagger``, ``svhn_cnn`` at the published 32x32x3 frame and
+full-size ``mlp_mixer_jet`` (the widths of ``repro/nn/models.py``), with
+weights made from a fixed seed:
 
 1. ``Flow.compile`` with the default solver and the "cheap" verify tier;
 2. ``design.save`` under the checkout, then ``Flow.load`` (zero solver
@@ -47,7 +48,7 @@ from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 
 NETWORKS = {
     "jet_tagger": models.jet_tagger,
-    "svhn_cnn": models.svhn_cnn,
+    "svhn_cnn": lambda: models.svhn_cnn(full_frame=True),
     "mlp_mixer_jet": lambda: models.mlp_mixer_jet(full_size=True),
 }
 SEED = 0

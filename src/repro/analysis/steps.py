@@ -547,10 +547,13 @@ def _step_pool(s: Any, flow: _Flow, rep: DiagnosticReport, loc: dict) -> None:
         rep.add("DA023", "pool step params incomplete", loc=loc, passname=_PASS)
         flow.exact = False
         return
-    if flow.shape != (h, w, c) or h % ph or w % pw:
+    # floor pooling: a remainder row or column is dropped, as the
+    # executors crop it; a flow that is not the declared shape, or a
+    # window that holds no whole block of it, is broken
+    if flow.shape != (h, w, c) or not (0 < ph <= h and 0 < pw <= w):
         rep.add(
             "DA021",
-            f"pool window ({ph},{pw}) does not tile flow shape {flow.shape} "
+            f"pool window ({ph},{pw}) does not fit flow shape {flow.shape} "
             f"(declared {(h, w, c)})",
             loc=loc, passname=_PASS,
         )

@@ -25,6 +25,7 @@ import os
 import time
 from dataclasses import dataclass, field
 from collections.abc import Callable
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +92,17 @@ class StepSpec:
     arrays: dict = field(default_factory=dict)
     table: int = -1
     body: list["StepSpec"] | None = None
+
+
+class CmvmStep(NamedTuple):
+    """One CMVM step of a design's pipeline, as ``CompiledDesign.cmvm_steps``
+    lists them."""
+
+    step: int  # index of the top-level step whose step{i}_{kind} scope runs it
+    kind: str  # "dense" or "conv"
+    executor: str  # "dot" (one exact MXU dot) or "adder_graph"
+    instances: int  # CMVM instances an event
+    macs: int  # multiply-accumulates an event: instances x n_in x n_out
 
 
 @dataclass
@@ -181,45 +193,47 @@ class CompiledDesign:
         exps = np.array([q_.exp if not q_.is_zero else 0 for q_ in self.out_qints])
         return y.astype(jnp.float32) * (2.0 ** exps).reshape(self.out_shape)
 
-    def _cmvm_steps(self) -> list[tuple[str, int]]:
-        """(executor, MACs an event) of each CMVM step, in pipeline order."""
+    @property
+    def cmvm_steps(self) -> list[CmvmStep]:
+        """Each CMVM step in pipeline order: the index of the top-level
+        step it runs under (a residual's body steps give the residual's),
+        its kind, its executor, its instances and its MACs an event."""
         mats = _dot_matrices(self.tables, self.programs, self.use_pallas)
-        return [
-            (
-                "adder_graph" if mats[s.table] is None else "dot",
-                n * self.tables[s.table].n_inputs * self.tables[s.table].n_outputs,
-            )
-            for s, n in _cmvm_instances(
-                self.step_specs, self.tables, int(np.prod(self.in_shape))
-            )
-        ]
+        out = []
+        for i, s, n in _cmvm_instances(
+            self.step_specs, self.tables, int(np.prod(self.in_shape))
+        ):
+            tab = self.tables[s.table]
+            ex = "adder_graph" if mats[s.table] is None else "dot"
+            out.append(CmvmStep(i, s.kind, ex, n, n * tab.n_inputs * tab.n_outputs))
+        return out
 
     @property
     def executors(self) -> list[str]:
         """Each CMVM step's executor, in pipeline order: "dot" (one exact
         MXU dot, see ``build_steps``) or "adder_graph"."""
-        return [ex for ex, _ in self._cmvm_steps()]
+        return [s.executor for s in self.cmvm_steps]
 
     @property
     def dot_share(self) -> float:
         """MACs of the CMVM steps that run as a dot over all CMVM MACs
         (1.0 for a design with no CMVM step)."""
-        steps = self._cmvm_steps()
-        total = sum(macs for _, macs in steps)
-        return sum(macs for ex, macs in steps if ex == "dot") / total if total else 1.0
+        steps = self.cmvm_steps
+        total = sum(s.macs for s in steps)
+        return sum(s.macs for s in steps if s.executor == "dot") / total if total else 1.0
 
     def summary(self) -> str:
         hdr = (
             f"{'layer':<20}{'shape':<14}{'adders':>8}{'LUTbits':>9}{'depth':>7}"
-            f"{'stages':>7}{'FFbits':>8}{'t[s]':>8}{'exec':>13}"
+            f"{'stages':>7}{'FFbits':>8}{'t[s]':>8}{'MACs/ev':>10}{'exec':>13}"
         )
         rows = [hdr, "-" * len(hdr)]
-        executors = self.executors
+        steps = self.cmvm_steps
         for k, r in enumerate(self.reports):
-            ex = executors[k] if k < len(executors) else "?"
+            macs, ex = (steps[k].macs, steps[k].executor) if k < len(steps) else ("?", "?")
             rows.append(
                 f"{r.name:<20}{r.shape:<14}{r.adders:>8}{r.cost_bits:>9}{r.depth:>7}"
-                f"{r.stages:>7}{r.ff_bits:>8}{r.solver_time_s:>8.2f}{ex:>13}"
+                f"{r.stages:>7}{r.ff_bits:>8}{r.solver_time_s:>8.2f}{macs:>10}{ex:>13}"
             )
         rows.append("-" * len(hdr))
         rows.append(
@@ -268,20 +282,20 @@ def _dot_matrices(tables: list, programs: list | None, use_pallas: bool) -> list
     return [dot_matrix(t, p) for t, p in zip(tables, programs, strict=True)]
 
 
-def _cmvm_instances(specs: list[StepSpec], tables: list, width: int, out=None) -> list:
-    """(spec, instances an event) of each CMVM step, in pipeline order,
-    for a pipeline fed ``width`` values an event."""
+def _cmvm_instances(specs: list[StepSpec], tables: list, width: int, top=None, out=None):
+    """(top-level step index, spec, instances an event) of each CMVM step,
+    in pipeline order, for a pipeline fed ``width`` values an event."""
     out = [] if out is None else out
-    for s in specs:
-        p = s.params
+    for i, s in enumerate(specs):
+        p, at = s.params, i if top is None else top
         if s.kind in ("dense", "conv"):
             n = width // p["d_in"] if s.kind == "dense" else p["oh"] * p["ow"]
-            out.append((s, n))
+            out.append((at, s, n))
             width = n * tables[s.table].n_outputs
         elif s.kind in ("maxpool", "avgpool"):
-            width //= p["ph"] * p["pw"]
+            width = (p["h"] // p["ph"]) * (p["w"] // p["pw"]) * p["c"]
         elif s.kind == "residual":
-            _cmvm_instances(s.body or [], tables, width, out)
+            _cmvm_instances(s.body or [], tables, width, at, out)
     return out
 
 
@@ -364,10 +378,14 @@ def _build_step(spec: StepSpec, tables: list, mats: list, use_pallas: bool) -> C
     if kind in ("maxpool", "avgpool"):
         h, w, c, ph, pw = p["h"], p["w"], p["c"], p["ph"], p["pw"]
 
+        # floor pooling: a remainder row or column (h % ph, w % pw) is
+        # dropped, as the qint walk drops it; a tiling window crops nothing
         def step(v, h=h, w=w, c=c, ph=ph, pw=pw, is_max=(kind == "maxpool")):
-            x = v.reshape(-1, h // ph, ph, w // pw, pw, c)
+            n, oh, ow = v.shape[0], h // ph, w // pw
+            x = v.reshape(n, h, w, c)[:, : oh * ph, : ow * pw]
+            x = x.reshape(n, oh, ph, ow, pw, c)
             r = x.max(axis=(2, 4)) if is_max else x.sum(axis=(2, 4))
-            return r.reshape(v.shape[0], -1)
+            return r.reshape(n, -1)
 
         return step
     if kind == "residual":

@@ -130,14 +130,16 @@ def _build_numpy_step(spec, tables) -> Callable[[np.ndarray], np.ndarray]:
         h, w, c, ph, pw = p["h"], p["w"], p["c"], p["ph"], p["pw"]
 
         def step(v, h=h, w=w, c=c, ph=ph, pw=pw, is_max=(kind == "maxpool")):
-            x = v.reshape(-1, h // ph, ph, w // pw, pw, c)
+            n, oh, ow = v.shape[0], h // ph, w // pw
+            x = v.reshape(n, h, w, c)[:, : oh * ph, : ow * pw]
+            x = x.reshape(n, oh, ph, ow, pw, c)
             if is_max:
                 r = x.max(axis=(2, 4))
             else:
                 # numpy widens int32 sums to the platform int by default;
                 # pin int32 so wrap semantics match the jitted path
                 r = x.sum(axis=(2, 4), dtype=np.int32)
-            return r.reshape(v.shape[0], -1)
+            return r.reshape(n, -1)
 
         return step
     if kind == "residual":

@@ -233,6 +233,11 @@ def apply_model(
             k = spec.size[0] * spec.size[1]
             assert k & (k - 1) == 0, "AvgPool window must be a power of two"
             x = _pool(x, spec.size, jax.lax.add, 0.0) / k
+            if cur_quant is not None:
+                # the mean of k values on a grid lies on a grid k times
+                # finer, with the same range: the next bias rounds there
+                s = k.bit_length() - 1
+                cur_quant = QuantConfig(cur_quant.bits + s, cur_quant.int_bits, cur_quant.signed)
         elif isinstance(spec, Flatten):
             x = x.reshape(x.shape[0], -1)
         elif isinstance(spec, Residual):

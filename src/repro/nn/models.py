@@ -55,12 +55,15 @@ def jet_tagger(w_bits: int = 6, a_bits: int = 8):
     return model, (16,), in_quant
 
 
-def svhn_cnn(w_bits: int = 6, a_bits: int = 8):
+def svhn_cnn(w_bits: int = 6, a_bits: int = 8, full_frame: bool = False):
     """LeNet-like SVHN classifier (paper Fig. 8).
 
-    VALID convolutions, so the 32x32 SVHN frame is center-cropped to
-    30x30 (the standard hls4ml variant uses SAME padding; resource
-    counts are equivalent — the CMVM kernels are identical)."""
+    VALID convolutions and floor pooling (the standard hls4ml variant
+    uses SAME padding; resource counts are equivalent — the CMVM kernels
+    are identical).  ``full_frame=True`` takes the published 32x32x3 SVHN
+    frame: 32 -> 30 -> 15 -> 13 -> 6 -> 4 -> 2, the second pool dropping
+    the 13th row and column.  The default keeps the 30x30x3 crop that
+    designs and data built before floor pooling were made at."""
     wq, aq = _wq(w_bits), _act(a_bits)
     model = (
         QConv2D(16, (3, 3), w_quant=wq), ReLU(aq), MaxPool2D((2, 2)),
@@ -72,7 +75,8 @@ def svhn_cnn(w_bits: int = 6, a_bits: int = 8):
         QDense(10, wq),
     )
     in_quant = QuantConfig(8, 1, signed=False)  # pixel intensities [0,1)
-    return model, (30, 30, 3), in_quant
+    side = 32 if full_frame else 30
+    return model, (side, side, 3), in_quant
 
 
 def muon_tracker(w_bits: int = 6, a_bits: int = 8, d_in: int = 64):
