@@ -210,7 +210,8 @@ class CompileConfig(_ConfigBase):
     use_pallas           execute every CMVM through the Pallas adder-graph
                          kernel instead of the default executors (one
                          exact MXU dot where ``build_steps`` proves it,
-                         else the jnp adder graph); CPU backend only, in
+                         for a conv step one exact convolution, else
+                         the jnp adder graph); CPU backend only, in
                          interpret mode: the kernel does not lower for
                          the TPU, so ``build_steps`` raises there.
     jobs                 solver thread-pool width (None = cpu_count,

@@ -16,7 +16,10 @@ bit, provided that
 
 :func:`dot_matrix` proves those conditions from the step's own tables
 and the input intervals of its DAIS program, and returns ``M`` only
-then; :func:`cmvm_dot` runs it.  ``M`` is read out of the solved adder
+then; :func:`cmvm_dot` runs it, and :func:`cmvm_conv` runs it over every
+window of a VALID convolution, whose im2col columns are the step's
+inputs: each output is the same column sum, so the same proof covers
+it.  ``M`` is read out of the solved adder
 graph, not out of the float weights, so the dot serves the program's own
 function.
 """
@@ -92,6 +95,19 @@ def cmvm_dot(m: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     y = lax.dot_general(
         x.astype(jnp.bfloat16), m,
         (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return y.astype(jnp.int32)
+
+
+def cmvm_conv(m: jnp.ndarray, x: jnp.ndarray, strides: tuple[int, int]) -> jnp.ndarray:
+    """The VALID convolution of x int ``[B, h, w, cin]`` with m bfloat16
+    ``[kh, kw, cin, n_out]``, the :func:`dot_matrix` of the step's im2col
+    columns in their (dy, dx, c) order, channel minor; returns int32
+    ``[B, oh, ow, n_out]``, each window's ``patch @ M``."""
+    y = lax.conv_general_dilated(
+        x.astype(jnp.bfloat16), m, strides, "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
         preferred_element_type=jnp.float32,
     )
     return y.astype(jnp.int32)

@@ -41,7 +41,7 @@ from ..core.solver import (
 )
 from ..flow.config import UNSET, CompileConfig, SolverConfig, resolve_legacy
 from ..kernels.adder_graph import adder_graph_apply, compile_tables
-from ..kernels.adder_graph.dot import cmvm_dot, dot_matrix
+from ..kernels.adder_graph.dot import cmvm_conv, cmvm_dot, dot_matrix
 from ..obs import trace
 from .layers import (
     AvgPool2D,
@@ -100,7 +100,7 @@ class CmvmStep(NamedTuple):
 
     step: int  # index of the top-level step whose step{i}_{kind} scope runs it
     kind: str  # "dense" or "conv"
-    executor: str  # "dot" (one exact MXU dot) or "adder_graph"
+    executor: str  # "dot" (one exact MXU dot), "conv" (one exact convolution) or "adder_graph"
     instances: int  # CMVM instances an event
     macs: int  # multiply-accumulates an event: instances x n_in x n_out
 
@@ -204,23 +204,27 @@ class CompiledDesign:
             self.step_specs, self.tables, int(np.prod(self.in_shape))
         ):
             tab = self.tables[s.table]
-            ex = "adder_graph" if mats[s.table] is None else "dot"
+            ex = "adder_graph" if mats[s.table] is None else "conv" if s.kind == "conv" else "dot"
             out.append(CmvmStep(i, s.kind, ex, n, n * tab.n_inputs * tab.n_outputs))
         return out
 
     @property
     def executors(self) -> list[str]:
         """Each CMVM step's executor, in pipeline order: "dot" (one exact
-        MXU dot, see ``build_steps``) or "adder_graph"."""
+        MXU dot, see ``build_steps``), "conv" (a conv step's one exact
+        convolution) or "adder_graph"."""
         return [s.executor for s in self.cmvm_steps]
 
     @property
     def dot_share(self) -> float:
-        """MACs of the CMVM steps that run as a dot over all CMVM MACs
-        (1.0 for a design with no CMVM step)."""
+        """MACs of the CMVM steps that run on the MXU, as a "dot" or a
+        "conv", over all CMVM MACs (1.0 for a design with no CMVM step).
+        A conv step's MACs are the same under either executor, so the
+        share does not depend on which of the two runs it."""
         steps = self.cmvm_steps
         total = sum(s.macs for s in steps)
-        return sum(s.macs for s in steps if s.executor == "dot") / total if total else 1.0
+        on_mxu = sum(s.macs for s in steps if s.executor != "adder_graph")
+        return on_mxu / total if total else 1.0
 
     def summary(self) -> str:
         hdr = (
@@ -258,8 +262,10 @@ def build_steps(
 
     A CMVM step whose table ``repro.kernels.adder_graph.dot.dot_matrix``
     proves exact runs as one bfloat16 MXU dot of the matrix its adder
-    graph computes; every other step (no program for its table, a refused
-    proof, or ``use_pallas=True``) runs the adder graph itself.
+    graph computes, a conv step as one convolution of that matrix over
+    its input; every other step (no program for its table, a refused
+    proof, or ``use_pallas=True``) runs the adder graph itself, a conv
+    step on its im2col of kh·kw strided slices.
 
     ``use_pallas=True`` runs only on the CPU backend (interpret mode):
     the adder-graph Pallas kernel does not lower through Mosaic, so any
@@ -299,10 +305,8 @@ def _cmvm_instances(specs: list[StepSpec], tables: list, width: int, top=None, o
     return out
 
 
-def _build_cmvm_fn(spec: StepSpec, tables: list, mats: list, use_pallas: bool):
-    tab, mat = tables[spec.table], mats[spec.table]
-    if mat is not None:
-        mat = jnp.asarray(mat, jnp.bfloat16)
+def _shift_bias(spec: StepSpec) -> Callable:
+    """The step's own ``y << shift`` then ``+ bias``, over its last axis."""
     bias = (
         jnp.asarray(spec.arrays["bias"], jnp.int32) if "bias" in spec.arrays else None
     )
@@ -312,14 +316,24 @@ def _build_cmvm_fn(spec: StepSpec, tables: list, mats: list, use_pallas: bool):
         else None
     )
 
-    def cmvm(v, tab=tab, mat=mat, bias=bias, shift=shift, use_pallas=use_pallas):
-        if mat is None:
-            y = adder_graph_apply(tab, v, use_pallas=use_pallas)
-        else:
-            y = cmvm_dot(mat, v)
+    def tail(y, bias=bias, shift=shift):
         if shift is not None:
             y = y << shift
         return y + bias if bias is not None else y
+
+    return tail
+
+
+def _build_cmvm_fn(spec: StepSpec, tables: list, mats: list, use_pallas: bool):
+    tab, mat = tables[spec.table], mats[spec.table]
+    if mat is not None:
+        mat = jnp.asarray(mat, jnp.bfloat16)
+    tail = _shift_bias(spec)
+
+    def cmvm(v, tab=tab, mat=mat, use_pallas=use_pallas):
+        if mat is None:
+            return tail(adder_graph_apply(tab, v, use_pallas=use_pallas))
+        return tail(cmvm_dot(mat, v))
 
     return cmvm
 
@@ -338,10 +352,23 @@ def _build_step(spec: StepSpec, tables: list, mats: list, use_pallas: bool) -> C
 
         return step
     if kind == "conv":
-        f = _build_cmvm_fn(spec, tables, mats, use_pallas)
         h, w, cin = p["h"], p["w"], p["cin"]
         kh, kw, sh, sw = p["kh"], p["kw"], p["sh"], p["sw"]
         oh, ow = p["oh"], p["ow"]
+        mat = mats[spec.table]
+        if mat is not None:
+            # a proven table runs as one convolution: its rows are the
+            # im2col columns in their (dy, dx, c) order, so they reshape
+            # to the HWIO kernel, and each window's sum is the dot's
+            kernel = jnp.asarray(mat.reshape(kh, kw, cin, -1), jnp.bfloat16)
+            tail = _shift_bias(spec)
+
+            def step(v, h=h, w=w, cin=cin, sh=sh, sw=sw, kernel=kernel, tail=tail):
+                y = tail(cmvm_conv(kernel, v.reshape(-1, h, w, cin), (sh, sw)))
+                return y.reshape(y.shape[0], -1)  # [B, oh*ow*cout]
+
+            return step
+        f = _build_cmvm_fn(spec, tables, mats, use_pallas)
 
         def step(v, h=h, w=w, cin=cin, kh=kh, kw=kw, sh=sh, sw=sw, oh=oh, ow=ow, f=f):
             x = v.reshape(-1, h, w, cin)

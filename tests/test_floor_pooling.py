@@ -95,9 +95,10 @@ def test_cmvm_steps_count_each_step_and_its_executor(svhn):
     design = svhn[32][3]
     steps = design.cmvm_steps
     assert [(s.step, s.kind, s.executor, s.instances, s.macs) for s in steps] == [
-        (0, "conv", "dot", 30 * 30, 900 * 27 * 16),
-        (4, "conv", "dot", 13 * 13, 169 * 144 * 16),
-        (8, "conv", "dot", 4 * 4, 16 * 144 * 24),
+        # a proven conv step runs as one convolution
+        (0, "conv", "conv", 30 * 30, 900 * 27 * 16),
+        (4, "conv", "conv", 13 * 13, 169 * 144 * 16),
+        (8, "conv", "conv", 4 * 4, 16 * 144 * 24),
         # the average pool's sums reach 4 x 255: past the dot's exact range
         (12, "dense", "adder_graph", 1, 96 * 42),
         (15, "dense", "dot", 1, 42 * 64),
